@@ -73,13 +73,16 @@ def bench_env() -> dict:
 
     import jax
     import jaxlib
+
+    from repro.kernels.platform import resolve_interpret
     return {
         "host": hashlib.blake2b(socket.gethostname().encode(),
                                 digest_size=4).hexdigest(),
         "cpu_count": os.cpu_count(),
         "jax": jax.__version__,
         "jaxlib": jaxlib.__version__,
-        "interpret": True,      # the harness runs pallas interpret mode
+        # what the kernels resolved to on this backend
+        "interpret": resolve_interpret(),
         "timestamp": time.strftime("%Y-%m-%dT%H:%M:%S%z"),
     }
 

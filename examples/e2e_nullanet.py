@@ -12,8 +12,9 @@ The whole paper loop as one artifact (flow/):
    gate factoring -> synth -> sub-kernel scheduling).
 3. Executes the chained logic stack — input binarization, packed-word
    layer handoff, numeric argmax head — through all three backends:
-   jnp reference, Pallas fabric kernel (interpret), and batched
-   LogicEngine serving of the composed hidden-stack graph.
+   jnp reference, Pallas fabric kernel (compiled on a TPU, interpreted
+   on a CPU), and batched LogicEngine serving of the composed
+   hidden-stack graph.
 4. Reports accuracy parity (float / binarized / logic), per-layer gate &
    step counts, and the pipelined-simulator cycle estimate.
 
@@ -27,9 +28,11 @@ import json
 
 from repro.core.spec import CompileSpec
 from repro.flow import FlowConfig, run_flow
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--quick", action="store_true",
                     help="smaller task + fewer train steps (~8s)")

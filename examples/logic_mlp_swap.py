@@ -16,6 +16,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core.spec import CompileSpec
 from repro.data.synthetic import TokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import logic_mlp
 from repro.models.layers import rms_norm, softmax_xent
 from repro.models.transformer import init_params
@@ -38,6 +39,7 @@ def forward(params, cfg, tokens, ffn_fn):
 
 
 def main() -> None:
+    enable_compile_cache()
     cfg = get_config("qwen3-8b", smoke=True).with_(
         n_layers=2, d_model=48, d_ff=24, n_heads=4, n_kv_heads=2,
         head_dim=12, vocab_size=256)

@@ -16,6 +16,7 @@ from repro.core.scheduler import compile_graph
 from repro.core.spec import CompileSpec
 from repro.core.verilog import parse_verilog
 from repro.kernels.logic_dsp import logic_infer_bits
+from repro.launch.compile_cache import enable_compile_cache
 
 VERILOG = """
 module majority5_and_parity(a, b, c, d, e, maj, par);
@@ -35,6 +36,7 @@ endmodule
 
 
 def main() -> None:
+    enable_compile_cache()
     graph = parse_verilog(VERILOG)
     print(f"parsed: {graph.stats()}")
     res = PassManager.default().run(graph)   # pass-based optimization
@@ -52,7 +54,7 @@ def main() -> None:
 
     rng = np.random.default_rng(0)
     x = rng.integers(0, 2, (1000, 5)).astype(bool)
-    got = logic_infer_bits(prog, x)          # Pallas kernel (interpret)
+    got = logic_infer_bits(prog, x)          # Pallas kernel
     want = graph.evaluate(x)
     assert (got == want).all()
     maj = x.sum(axis=1) >= 3

@@ -17,11 +17,13 @@ import numpy as np
 
 from repro.core.gate_ir import random_graph
 from repro.core.spec import CompileSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import (FaultPolicy, FrontDoor, Priority, TrafficPattern,
                          build_trace, run_trace)
 
 
 async def main(quick: bool) -> None:
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     graph_a = random_graph(rng, 16, 300 if quick else 800, 10, locality=64)
     graph_b = random_graph(rng, 12, 200 if quick else 500, 8, locality=64)

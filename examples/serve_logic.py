@@ -20,10 +20,12 @@ import numpy as np
 
 from repro.core.gate_ir import random_graph
 from repro.core.spec import CompileSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import LogicEngine
 
 
 def main() -> None:
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     engine = LogicEngine(CompileSpec(n_unit=64), capacity=256)
     print(f"engine: capacity={engine.capacity} samples/invocation, "

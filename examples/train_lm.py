@@ -14,10 +14,12 @@ import jax
 
 from repro.configs import get_config
 from repro.launch.mesh import make_host_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import TrainConfig, Trainer
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--resume", action="store_true",

@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.core.gate_ir import LogicGraph, random_graph
 from repro.core.spec import CompileSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import ArtifactStore, LogicEngine
 
 
@@ -53,6 +54,7 @@ def parse_n_unit(v: str):
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--store", metavar="DIR", default=None,
                     help="store populated by tools/precompile.py; "

@@ -448,7 +448,7 @@ class WallClockModel:
 
 def measure_program_phases(prog, n_input_vectors: int, reps: int = 3,
                            seed: int = 0, *,
-                           interpret: bool = True) -> dict[str, float]:
+                           interpret: bool | None = None) -> dict[str, float]:
     """Min-over-reps seconds per phase for one compiled program.
 
     Warms the phased runner first (trace + compile excluded), then takes
@@ -493,7 +493,7 @@ def default_probe_units(quick: bool = True) -> tuple[int, ...]:
 
 def collect_probes(graphs: dict, n_units, n_input_vectors: int = 1024,
                    model: CostModel | None = None, reps: int = 3,
-                   *, interpret: bool = True) -> list[PhaseProbe]:
+                   *, interpret: bool | None = None) -> list[PhaseProbe]:
     """Compile and measure every (workload, n_unit) grid point.
 
     Probes compile with ``optimize="none"`` (the grid graphs are the

@@ -21,6 +21,11 @@ react differently to each — retry, reject, or crash loudly:
     with a machine-readable ``compile_failed`` reason instead of
     burning its deadline on retries.
 
+``FabricCapacityError``
+    A ``PermanentCompileError`` raised when a program's address file or
+    step records do not fit the fabric kernel's VMEM/SMEM; it names the
+    bytes needed and offered.
+
 ``ArtifactIntegrityError``
     A ``PermanentCompileError`` specific to the persistence layer
     (core/artifact_store.py): a store entry failed verification —
@@ -53,6 +58,14 @@ class PermanentCompileError(CompileError):
     """Compilation failed and retrying cannot help."""
 
     retryable = False
+
+
+class FabricCapacityError(PermanentCompileError):
+    """A compiled program does not fit the fabric kernel's on-chip memory.
+
+    Raised by the kernel launch wrappers (kernels/logic_dsp/kernel.py)
+    with the bytes the launch needs and the bytes the core offers; the
+    program is never silently run on another path instead."""
 
 
 class ArtifactIntegrityError(PermanentCompileError):

@@ -153,7 +153,7 @@ class LogicClassifier:
         input->output instead of concatenating partition outputs."""
         if backend not in self._runners:
             arrs = [program_arrays(layer.program) for layer in self.layers]
-            kw = dict(interpret=True, use_ref=(backend == "reference"))
+            use_ref = backend == "reference"
 
             def run(bits):
                 words = pack_bits_jnp(bits)
@@ -161,7 +161,7 @@ class LogicClassifier:
                     words = forward_words(
                         a["src_a"], a["src_b"], a["dst"], a["opcode"],
                         a["step_branch"], a["output_addrs"], words,
-                        n_addr=a["n_addr"], **kw)
+                        n_addr=a["n_addr"], use_ref=use_ref)
                 return unpack_bits_jnp(words, bits.shape[0])
 
             self._runners[backend] = jax.jit(run)

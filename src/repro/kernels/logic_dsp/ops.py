@@ -114,7 +114,7 @@ def program_arrays(prog: LogicProgram, pad_unit: int = 8) -> dict:
 
 def forward_words(src_a, src_b, dst, opcode, step_branch, output_addrs,
                   words: jnp.ndarray, *, n_addr: int,
-                  block_w: int = _k.LANE, interpret: bool = True,
+                  block_w: int = _k.LANE, interpret: bool | None = None,
                   use_ref: bool = False) -> jnp.ndarray:
     """Word-level program execution: (n_inputs, W) -> (n_outputs, W) int32.
 
@@ -141,7 +141,7 @@ def forward_words(src_a, src_b, dst, opcode, step_branch, output_addrs,
 
 
 def logic_forward(prog: LogicProgram, input_words: jnp.ndarray,
-                  block_w: int = _k.LANE, interpret: bool = True,
+                  block_w: int = _k.LANE, interpret: bool | None = None,
                   use_ref: bool = False) -> jnp.ndarray:
     """Packed-word forward: (n_inputs, W) int32 -> (n_outputs, W) int32."""
     arrs = program_arrays(prog)
@@ -153,7 +153,7 @@ def logic_forward(prog: LogicProgram, input_words: jnp.ndarray,
 
 
 def infer_runner(prog: LogicProgram, block_w: int = _k.LANE,
-                 interpret: bool = True, use_ref: bool = False):
+                 interpret: bool | None = None, use_ref: bool = False):
     """The program's fused pack -> execute -> unpack jit runner, cached ON
     the program object per kernel config.
 
@@ -186,7 +186,7 @@ def infer_runner(prog: LogicProgram, block_w: int = _k.LANE,
 
 
 def logic_infer_bits(prog: LogicProgram, bits: np.ndarray | jnp.ndarray,
-                     block_w: int = _k.LANE, interpret: bool = True,
+                     block_w: int = _k.LANE, interpret: bool | None = None,
                      use_ref: bool = False) -> np.ndarray:
     """Boolean convenience wrapper: (batch, n_inputs) -> (batch, n_outputs).
 
@@ -240,7 +240,7 @@ def _host_streams(prog: LogicProgram, pad_unit: int = 8) -> dict:
 
 
 def phased_infer_bits(prog: LogicProgram, bits: np.ndarray | jnp.ndarray,
-                      block_w: int = _k.LANE, interpret: bool = True,
+                      block_w: int = _k.LANE, interpret: bool | None = None,
                       use_ref: bool = False
                       ) -> tuple[np.ndarray, dict[str, float]]:
     """One inference split into the four calibration phases.
@@ -372,7 +372,7 @@ def _mega_forward_ref(mega: MegaProgram, arrs: dict,
 
 
 def mega_forward_words(mega: MegaProgram, words: jnp.ndarray, *,
-                       block_w: int = _k.LANE, interpret: bool = True,
+                       block_w: int = _k.LANE, interpret: bool | None = None,
                        use_ref: bool = False) -> jnp.ndarray:
     """Word-level mega execution: (n_inputs, W) -> (n_outputs, W) int32 in
     ONE kernel launch (or the stage-chained jnp reference)."""
@@ -392,7 +392,7 @@ def mega_forward_words(mega: MegaProgram, words: jnp.ndarray, *,
 
 
 def mega_infer_runner(mega: MegaProgram, block_w: int = _k.LANE,
-                      interpret: bool = True, use_ref: bool = False):
+                      interpret: bool | None = None, use_ref: bool = False):
     """Fused pack -> megakernel -> unpack jit, cached on the mega object
     (one trace per batch shape per config — the single-launch analogue of
     :func:`infer_runner`)."""
@@ -413,7 +413,7 @@ def mega_infer_runner(mega: MegaProgram, block_w: int = _k.LANE,
 
 
 def mega_infer_bits(mega: MegaProgram, bits: np.ndarray | jnp.ndarray,
-                    block_w: int = _k.LANE, interpret: bool = True,
+                    block_w: int = _k.LANE, interpret: bool | None = None,
                     use_ref: bool = False) -> np.ndarray:
     """Boolean convenience wrapper over the megakernel:
     (batch, n_inputs) -> (batch, n_outputs) in one launch."""

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import resolve_interpret
+
 
 def _xnor_kernel(a_ref, b_ref, out_ref, acc_ref, *, k_bits: int, n_kw: int):
     @pl.when(pl.program_id(2) == 0)
@@ -42,11 +44,13 @@ def _xnor_kernel(a_ref, b_ref, out_ref, acc_ref, *, k_bits: int, n_kw: int):
 @functools.partial(jax.jit,
                    static_argnames=("k_bits", "bm", "bn", "bk", "interpret"))
 def xnor_gemm_pallas(a_packed: jnp.ndarray, b_packed: jnp.ndarray, *,
-                     k_bits: int, bm: int = 128, bn: int = 128, bk: int = 16,
-                     interpret: bool = True) -> jnp.ndarray:
+                     k_bits: int, bm: int = 128, bn: int = 128, bk: int = 128,
+                     interpret: bool | None = None) -> jnp.ndarray:
     """a_packed: (M, Kw) int32; b_packed: (N, Kw) int32 -> (M, N) int32.
 
-    M % bm == N % bn == Kw % bk == 0 (caller pads). Zero-padding BOTH
+    M % bm == N % bn == Kw % bk == 0 (caller pads). Mosaic wants a
+    lane-dense ``bk`` (a multiple of 128 words) and ``bn`` (the output
+    block's lanes); the interpreter takes any. Zero-padding BOTH
     operands' K-words is safe: pad XOR pad = 0 contributes nothing to the
     hamming count, and ``k_bits`` counts only real bits.
     """
@@ -63,5 +67,5 @@ def xnor_gemm_pallas(a_packed: jnp.ndarray, b_packed: jnp.ndarray, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a_packed, b_packed)

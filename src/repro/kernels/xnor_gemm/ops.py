@@ -29,7 +29,7 @@ def _pad_cols(x: jnp.ndarray, mult: int) -> jnp.ndarray:
 
 
 def xnor_gemm(a_bits: jnp.ndarray, b_bits: jnp.ndarray, *, bm: int = 128,
-              bn: int = 128, bk: int = 16, interpret: bool = True
+              bn: int = 128, bk: int = 128, interpret: bool | None = None
               ) -> jnp.ndarray:
     """Binarized +-1 GEMM: a (M, K) {0,1} x b (N, K) {0,1} -> (M, N) int32."""
     m, k = a_bits.shape

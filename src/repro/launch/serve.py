@@ -13,11 +13,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import init_params
 from repro.serve import Request, RequestBatcher, decode_step, prefill
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ARCH_IDS)
     ap.add_argument("--smoke", action="store_true")

@@ -16,10 +16,12 @@ import importlib
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.registry import _MODULES
 from repro.launch.mesh import make_host_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import TrainConfig, Trainer
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ARCH_IDS)
     ap.add_argument("--smoke", action="store_true",

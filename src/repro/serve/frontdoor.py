@@ -499,7 +499,16 @@ class FrontDoor:
             for ticket in batch:
                 await self._dispatch(ticket)
             if self._inflight:
-                finished = await loop.run_in_executor(None, self._step)
+                try:
+                    finished = await loop.run_in_executor(None, self._step)
+                except Exception as exc:
+                    # a wave that raised (a kernel that failed to compile
+                    # or run) fails the requests it carried, so their
+                    # submitters see the error instead of waiting forever
+                    for ticket in self._inflight.values():
+                        if not ticket.future.done():
+                            ticket.future.set_exception(exc)
+                    raise
                 self._route(finished)
                 continue
             if batch:

@@ -48,7 +48,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 
 from repro.core.artifact_store import ArtifactStore, store_key
@@ -729,7 +728,9 @@ class LogicEngine:
         dropped (FIFO). ``None`` (default) retains until claimed — set a
         bound for fire-and-forget traffic so unclaimed results cannot
         grow without limit.
-      use_ref / interpret / block_w: forwarded to the kernel layer.
+      use_ref / interpret / block_w: forwarded to the kernel layer
+        (``interpret=None`` resolves from the backend:
+        :func:`~repro.kernels.platform.resolve_interpret`).
     """
 
     def __init__(self, spec: CompileSpec | int | None = None, *,
@@ -739,7 +740,7 @@ class LogicEngine:
                  max_programs: int | None = None,
                  store: ArtifactStore | None = None,
                  max_retained: int | None = None, use_ref: bool = False,
-                 interpret: bool = True, block_w: int = _k.LANE,
+                 interpret: bool | None = None, block_w: int = _k.LANE,
                  n_unit=_UNSET, alloc=_UNSET, max_gates=_UNSET,
                  optimize=_UNSET):
         self.spec = resolve_spec(spec, caller="LogicEngine", n_unit=n_unit,
@@ -855,8 +856,8 @@ class LogicEngine:
             # batch rows -> devices; each shard packs/serves its own
             # capacity/n_dev samples = W/n_dev words of the word axis.
             spec = batch_pspec(self.mesh, self.capacity, 2)
-            run = shard_map(run, mesh=self.mesh, in_specs=(spec,),
-                            out_specs=spec, check_rep=False)
+            run = jax.shard_map(run, mesh=self.mesh, in_specs=(spec,),
+                                out_specs=spec, check_vma=False)
         return jax.jit(run)
 
     # -- request lifecycle ---------------------------------------------------

@@ -12,13 +12,14 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np, tempfile
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import init_params, param_shapes
 from repro.train import sharding as shd
 from repro.train.checkpoint import CheckpointManager
 
 cfg = get_config("qwen3-8b", smoke=True)
-mesh_a = jax.make_mesh((4, 2), ("data", "model"))
-mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+mesh_a = make_mesh((4, 2), ("data", "model"))
+mesh_b = make_mesh((2, 4), ("data", "model"))
 shapes = param_shapes(cfg)
 shard_a = shd.param_shardings(cfg, mesh_a, shapes)
 shard_b = shd.param_shardings(cfg, mesh_b, shapes)

@@ -300,6 +300,28 @@ def test_permanent_compile_failure_sheds_immediately(rng):
     _run(go())
 
 
+def test_failed_wave_fails_its_requests(rng):
+    """An engine wave that raises (a kernel that failed to compile or
+    run) reaches the submitters of the requests it carried — they never
+    wait forever — and stopping the door re-raises it."""
+    g = _graph(rng)
+
+    async def go():
+        door = _door()
+        door.register("a", g)
+
+        def broken_step():
+            raise RuntimeError("kernel failed to compile")
+        door.engine.step = broken_step
+        with pytest.raises(RuntimeError, match="failed to compile"):
+            await door.submit("a", rng.integers(0, 2, (4, g.n_inputs))
+                              .astype(bool))
+        with pytest.raises(RuntimeError, match="failed to compile"):
+            await door.stop(drain=False)
+
+    _run(go())
+
+
 def test_error_taxonomy_classification():
     assert is_transient(TransientCompileError("x"))
     assert not is_transient(PermanentCompileError("x"))

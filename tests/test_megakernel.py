@@ -201,6 +201,64 @@ def test_megakernel_is_single_launch():
 
 
 # ---------------------------------------------------------------------------
+# record blocks, capacity, and the backend choice
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("mode", ["mono", "chain", "parallel"])
+def test_record_blocks_stream_through_both_slots(monkeypatch, mode):
+    """Programs longer than one SMEM record block: with the record budget
+    cut to 16-step blocks, every stage walks several blocks through both
+    slots (prefetching the next while one runs) and ends on a partial
+    block — and stays bit-exact."""
+    monkeypatch.setattr(_k, "SMEM_RECORD_BYTES",
+                        2 * 4 * 16 * _k.record_words(8))
+    assert _k.block_steps(8, 1000) == 16
+    rng = np.random.default_rng(20)
+    if mode == "parallel":
+        graphs = [_layer(rng, 6, 300, 5), _layer(rng, 6, 250, 4)]
+    else:
+        graphs = [_layer(rng, 6, 300, 5), _layer(rng, 5, 250, 4)]
+    progs = _chain_progs(graphs)
+    assert all(p.n_steps > 2 * 16 and p.n_steps % 16 for p in progs)
+    bits = _bits(rng, 70, 6)
+    if mode == "mono":
+        got, want = logic_infer_bits(progs[0], bits), graphs[0].evaluate(bits)
+    else:
+        mega = build_megaprogram(progs, mode=mode)
+        got, want = mega_infer_bits(mega, bits), \
+            execute_megaprogram_np(mega, bits)
+    assert (got == want).all()
+
+
+def test_program_that_cannot_fit_raises_with_bytes():
+    """An address file beyond the core's VMEM is a typed, permanent
+    compile failure naming the bytes — never a silent fallback."""
+    from repro.core.errors import FabricCapacityError, PermanentCompileError
+    from repro.kernels.logic_dsp.ops import program_arrays
+    rng = np.random.default_rng(21)
+    prog = compile_graph(_layer(rng, 6, 40, 4),
+                         CompileSpec(n_unit=8, optimize="none"))
+    a = program_arrays(prog)
+    words = jnp.zeros((6, 128), jnp.int32)
+    with pytest.raises(FabricCapacityError,
+                       match=r"needs \d+ bytes of VMEM") as ei:
+        _k.logic_pallas_call(a["src_a"], a["src_b"], a["dst"], a["opcode"],
+                             a["step_branch"], words, a["output_addrs"],
+                             n_addr=300_000)
+    assert isinstance(ei.value, PermanentCompileError)
+
+
+def test_interpret_resolves_from_backend():
+    """One helper picks compiled vs interpreted: the interpreter on a CPU
+    backend, Mosaic on a TPU; an explicit choice is honoured."""
+    import jax
+    from repro.kernels.platform import resolve_interpret
+    assert resolve_interpret() is (jax.default_backend() != "tpu")
+    assert resolve_interpret(True) is True
+    assert resolve_interpret(False) is False
+
+
+# ---------------------------------------------------------------------------
 # builder validation
 # ---------------------------------------------------------------------------
 

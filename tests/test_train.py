@@ -8,6 +8,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.data.synthetic import TokenPipeline
+from repro.launch.mesh import make_mesh
 from repro.optim import (adamw_init, adamw_update, clip_by_global_norm,
                          compress_int8, decompress_int8, cosine_schedule,
                          wsd_schedule, ef_compress)
@@ -155,7 +156,7 @@ def test_heartbeat_detects_dead_host():
 
 def _mk_trainer(tmp, **tc_kw):
     cfg = get_config("qwen3-8b", smoke=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     tc = TrainConfig(lr=1e-3, warmup_steps=2, total_steps=50,
                      checkpoint_every=5, checkpoint_dir=str(tmp), **tc_kw)
     return Trainer(cfg, tc, mesh, global_batch=8, seq_len=32)

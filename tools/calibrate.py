@@ -114,6 +114,9 @@ def main(argv: list[str] | None = None) -> int:
         src = os.path.join(os.path.dirname(__file__), "..", "src")
         env["PYTHONPATH"] = os.pathsep.join(
             p for p in (src, env.get("PYTHONPATH")) if p)
+        # this process holds the accelerator; the child only loads and
+        # resolves, so it stays on the CPU
+        env["JAX_PLATFORMS"] = "cpu"
         proc = subprocess.run(
             [sys.executable, "-c", _VERIFY_SNIPPET, args.store, args.name],
             env=env, capture_output=True, text=True)
