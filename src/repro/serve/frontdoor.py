@@ -55,12 +55,20 @@ from dataclasses import dataclass, field
 from enum import IntEnum
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.artifact_store import ArtifactStore
 from repro.core.errors import TransientCompileError, is_transient
 from repro.core.gate_ir import LogicGraph
 from repro.core.spec import CompileSpec
 from repro.serve.logic_engine import LogicEngine
+
+#: ``jax.profiler`` span names of the front door's synchronous work: one
+#: ``dispatch`` per ticket handed to the engine, one ``route`` per engine
+#: wave, one ``complete`` per request inside it
+DISPATCH_SPAN = "logic.frontdoor.dispatch"
+ROUTE_SPAN = "logic.frontdoor.route"
+COMPLETE_SPAN = "logic.frontdoor.complete"
 
 
 class Priority(IntEnum):
@@ -306,6 +314,7 @@ class FrontDoor:
         self.goodput_samples = 0        # samples completed in-deadline
         self.shed_by_code: dict[str, int] = {}
         self._latencies: list[float] = []
+        self._queue_waits: list[float] = []     # arrival -> engine.submit
 
     # -- tenancy -------------------------------------------------------------
 
@@ -477,7 +486,8 @@ class FrontDoor:
         if not ticket.future.done():
             ticket.future.set_exception(RequestRejected(reason))
 
-    def _complete(self, ticket: _Ticket, result: np.ndarray) -> None:
+    def _complete(self, ticket: _Ticket, result: np.ndarray) -> float:
+        """Resolve a served ticket; returns its latency (seconds)."""
         now = self._clock()
         latency = now - ticket.arrival_t
         self._latencies.append(latency)
@@ -489,6 +499,7 @@ class FrontDoor:
             self.goodput_samples += ticket.n_samples
         if not ticket.future.done():
             ticket.future.set_result(result)
+        return latency
 
     # -- the dispatch loop ---------------------------------------------------
 
@@ -585,9 +596,13 @@ class FrontDoor:
                     self._reject(ticket, "deadline_expired",
                                  detail="expired during injected delay")
                     return
+        queued = self._clock() - ticket.arrival_t
         try:
             self._compile_faults_armed = True
-            uid = self.engine.submit(ticket.tenant.graph, ticket.bits)
+            with TraceAnnotation(DISPATCH_SPAN, samples=ticket.n_samples,
+                                 queued_us=queued * 1e6) as span:
+                uid = self.engine.submit(ticket.tenant.graph, ticket.bits)
+                span.set_metadata(uid=uid)
         except Exception as exc:
             ticket.tenant.inflight -= 1
             if is_transient(exc):
@@ -597,6 +612,7 @@ class FrontDoor:
             return
         finally:
             self._compile_faults_armed = False
+        self._queue_waits.append(queued)
         self._inflight[uid] = ticket
         self._inflight_samples += ticket.n_samples
 
@@ -635,14 +651,17 @@ class FrontDoor:
         return finished
 
     def _route(self, finished: list[int]) -> None:
-        for uid in finished:
-            ticket = self._inflight.pop(uid, None)
-            if ticket is None:          # engine-level submitter wasn't us
-                continue
-            result = self.engine.result(uid)
-            ticket.tenant.inflight -= 1
-            self._inflight_samples -= ticket.n_samples
-            self._complete(ticket, result)
+        with TraceAnnotation(ROUTE_SPAN, requests=len(finished)):
+            for uid in finished:
+                ticket = self._inflight.pop(uid, None)
+                if ticket is None:      # engine-level submitter wasn't us
+                    continue
+                with TraceAnnotation(COMPLETE_SPAN, uid=uid) as span:
+                    result = self.engine.result(uid)
+                    ticket.tenant.inflight -= 1
+                    self._inflight_samples -= ticket.n_samples
+                    latency = self._complete(ticket, result)
+                    span.set_metadata(latency_us=latency * 1e6)
 
     # -- metrics -------------------------------------------------------------
 
@@ -651,19 +670,32 @@ class FrontDoor:
         return self._n_queued
 
     def reset_metrics(self) -> None:
-        """Zero the request counters and latency window (e.g. after the
-        compile/jit warmup waves), so steady-state measurements aren't
-        polluted by cold starts.  The wave-time window, tenant registry,
-        and engine/cache state stay — they ARE the warm state."""
+        """Zero the request counters and the latency and queue-wait
+        windows (e.g. after the compile/jit warmup waves), so
+        steady-state measurements aren't polluted by cold starts.  Both
+        windows time from the call of :meth:`submit`, on the front door's
+        clock.  The wave-time window, tenant registry, and engine/cache
+        state stay — they ARE the warm state."""
         self.offered = self.admitted = self.completed = 0
         self.retries = self.deadline_misses = self.goodput_samples = 0
         self.shed_by_code = {}
         self._latencies = []
+        self._queue_waits = []
         for t in self._tenants.values():
             t.submitted = t.completed = t.shed = 0
 
     def metrics(self) -> dict:
+        """Counters and percentiles since the last :meth:`reset_metrics`.
+
+        Latency (``latency_p50_ms``/``latency_p99_ms``, completed
+        requests) and queue wait (``queue_wait_p50_ms``/
+        ``queue_wait_p99_ms``, requests handed to the engine) both start
+        at the call of :meth:`submit`, on the front door's clock
+        (``time.monotonic``): a client's own delay before that call is
+        not in them.  Queue wait ends at the hand-off to
+        ``engine.submit``; latency at the result."""
         lat = np.asarray(self._latencies, dtype=float)
+        wait = np.asarray(self._queue_waits, dtype=float)
         shed = int(sum(self.shed_by_code.values()))
         return {
             "offered": self.offered,
@@ -680,6 +712,10 @@ class FrontDoor:
                                if lat.size else None),
             "latency_p99_ms": (float(np.percentile(lat, 99)) * 1e3
                                if lat.size else None),
+            "queue_wait_p50_ms": (float(np.percentile(wait, 50)) * 1e3
+                                  if wait.size else None),
+            "queue_wait_p99_ms": (float(np.percentile(wait, 99)) * 1e3
+                                  if wait.size else None),
             "wave_est_ms": (None if self.wave_s is None
                             else self.wave_s * 1e3),
             "faults_injected": (dict(self.fault_policy.injected)

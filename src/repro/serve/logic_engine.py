@@ -48,6 +48,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh
 
 from repro.core.artifact_store import ArtifactStore, store_key
@@ -64,6 +65,15 @@ from repro.kernels.logic_dsp.ops import (mega_arrays, mega_forward_words,
                                          pack_bits_jnp, unpack_bits_jnp)
 from repro.serve.batcher import SlotTable
 from repro.train.sharding import batch_pspec
+
+#: ``jax.profiler`` span names of one :meth:`LogicEngine.step` wave: the
+#: wave, then its phases in order (children of the wave, never overlapping)
+STEP_SPAN = "logic.engine.step"
+ADMIT_SPAN = "logic.engine.admit"
+SLAB_SPAN = "logic.engine.slab"
+LAUNCH_SPAN = "logic.engine.launch"
+FETCH_SPAN = "logic.engine.fetch"
+SCATTER_SPAN = "logic.engine.scatter"
 
 
 # ---------------------------------------------------------------------------
@@ -847,11 +857,16 @@ class LogicEngine:
         kw = dict(block_w=self.block_w, interpret=self.interpret,
                   use_ref=self.use_ref)
 
-        def run(bits: jnp.ndarray) -> jnp.ndarray:
-            words = pack_bits_jnp(bits)
+        # stable names in the profiler's trace: the host event reads
+        # ``PjitFunction(logic_wave)``, the device ops carry the scopes
+        def logic_wave(bits: jnp.ndarray) -> jnp.ndarray:
+            with jax.named_scope("logic_pack"):
+                words = pack_bits_jnp(bits)
             ow = mega_forward_words(mega, words, **kw)
-            return unpack_bits_jnp(ow, bits.shape[0])
+            with jax.named_scope("logic_unpack"):
+                return unpack_bits_jnp(ow, bits.shape[0])
 
+        run = logic_wave
         if self.shard:
             # batch rows -> devices; each shard packs/serves its own
             # capacity/n_dev samples = W/n_dev words of the word axis.
@@ -946,11 +961,51 @@ class LogicEngine:
         Serves the longest-waiting non-empty program queue (FIFO across
         keys), admitting chunks into slot rows until the table is full,
         then runs ONE fused fabric invocation for all of them. Returns the
-        uids completed this wave.
+        uids completed this wave; a call with nothing queued is no wave.
+
+        A wave is the ``jax.profiler`` span ``logic.engine.step``
+        (:data:`STEP_SPAN`) with the stats ``rows`` (samples admitted),
+        ``capacity``, ``chunks`` and ``finished`` (requests completed).
+        Its children, in order and never overlapping:
+        ``logic.engine.admit`` (queue choice and slot acquisition),
+        ``logic.engine.slab`` (the input slab), ``logic.engine.launch``
+        (the fused runner call, up to its returned ``jax.Array``),
+        ``logic.engine.fetch`` (waiting for the device and copying the
+        result to the host) and ``logic.engine.scatter`` (results into
+        their requests, slot release, retirement, occupancy counters).
+        The spans are always on; without an active profiler each costs
+        about a microsecond.
         """
-        key = next((k for k, q in self._queues.items() if q), None)
-        if key is None:
+        if self.idle:
             return []
+        with TraceAnnotation(STEP_SPAN, capacity=self.capacity) as wave:
+            with TraceAnnotation(ADMIT_SPAN):
+                key, entry, admitted = self._admit_wave()
+            if not admitted:
+                return []
+            n_active = sum(c.n for c, _ in admitted)
+            with TraceAnnotation(SLAB_SPAN):
+                bits = np.zeros((self.capacity, entry.n_inputs), dtype=bool)
+                for chunk, rows in admitted:
+                    bits[rows] = chunk.req.inputs[chunk.lo:chunk.hi]
+            # hand the numpy slab straight to the jit runner: its C
+            # argument path transfers it far cheaper than an eager
+            # jnp.asarray round trip (which cost more than the kernel
+            # itself at small waves)
+            with TraceAnnotation(LAUNCH_SPAN):
+                out_dev = entry.runners[self._exec_key](bits)
+            with TraceAnnotation(FETCH_SPAN):
+                out = np.asarray(out_dev)
+            with TraceAnnotation(SCATTER_SPAN):
+                finished = self._scatter(key, admitted, out, n_active)
+            wave.set_metadata(rows=n_active, chunks=len(admitted),
+                              finished=len(finished))
+        return finished
+
+    def _admit_wave(self) -> tuple[tuple, CompiledEntry, list]:
+        """Pick the longest-waiting non-empty queue and acquire slot rows
+        for its chunks: ``(key, entry, [(chunk, rows)])``."""
+        key = next(k for k, q in self._queues.items() if q)
         queue = self._queues[key]
         entry = self.cache.peek(key)
         if entry is None:
@@ -968,19 +1023,14 @@ class LogicEngine:
             if rows is None:
                 break
             admitted.append((queue.popleft(), rows))
-        if not admitted:
-            return []
+        return key, entry, admitted
 
-        bits = np.zeros((self.capacity, entry.n_inputs), dtype=bool)
-        for chunk, rows in admitted:
-            bits[rows] = chunk.req.inputs[chunk.lo:chunk.hi]
-        # hand the numpy slab straight to the jit runner: its C argument
-        # path transfers it far cheaper than an eager jnp.asarray round
-        # trip (which cost more than the kernel itself at small waves)
-        out = np.asarray(entry.runners[self._exec_key](bits))
-
+    def _scatter(self, key: tuple, admitted: list, out: np.ndarray,
+                 n_active: int) -> list[int]:
+        """Results into their requests, rows back to the slot table,
+        completed requests retired, occupancy counted; returns the
+        completed uids."""
         finished: list[int] = []
-        n_active = sum(c.n for c, _ in admitted)
         for chunk, rows in admitted:
             chunk.req.result[chunk.lo:chunk.hi] = out[rows]
             chunk.req.pending_chunks -= 1
@@ -992,7 +1042,7 @@ class LogicEngine:
         self.invocations += 1
         self.samples_served += n_active
         self._occupancy_sum += n_active / self.capacity
-        if not queue:
+        if not self._queues[key]:
             del self._queues[key]
         return finished
 

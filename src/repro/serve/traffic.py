@@ -118,7 +118,9 @@ class TrafficReport:
     row source).  ``deadline-miss`` counts admitted requests that
     failed their deadline either way — completed late or expired before
     dispatch; ``shed`` counts every :class:`RequestRejected`; goodput
-    counts only samples completed in-deadline."""
+    counts only samples completed in-deadline.  Latencies run from each
+    request's due time; ``lateness_s`` holds how long after it the
+    generator sent each request."""
 
     offered: int = 0
     completed: int = 0
@@ -127,6 +129,7 @@ class TrafficReport:
     goodput_samples: int = 0
     elapsed_s: float = 0.0
     latencies_s: list = field(default_factory=list)
+    lateness_s: list = field(default_factory=list)
     shed_by_code: dict = field(default_factory=dict)
     per_tenant: dict = field(default_factory=dict)
 
@@ -144,6 +147,16 @@ class TrafficReport:
     def p99_ms(self) -> float | None:
         p = self._pct(99)
         return None if p is None else p * 1e3
+
+    @property
+    def late_p50_ms(self) -> float | None:
+        if not self.lateness_s:
+            return None
+        return float(np.percentile(np.asarray(self.lateness_s), 50)) * 1e3
+
+    @property
+    def late_max_ms(self) -> float | None:
+        return max(self.lateness_s) * 1e3 if self.lateness_s else None
 
     @property
     def shed_rate(self) -> float:
@@ -167,6 +180,10 @@ class TrafficReport:
             "goodput_samples_per_s": round(self.goodput_sps, 1),
             "p50_ms": None if self.p50_ms is None else round(self.p50_ms, 3),
             "p99_ms": None if self.p99_ms is None else round(self.p99_ms, 3),
+            "late_p50_ms": (None if self.late_p50_ms is None
+                            else round(self.late_p50_ms, 3)),
+            "late_max_ms": (None if self.late_max_ms is None
+                            else round(self.late_max_ms, 3)),
             "elapsed_s": round(self.elapsed_s, 4),
             "per_tenant": dict(self.per_tenant),
         }
@@ -177,22 +194,25 @@ async def run_trace(door: FrontDoor, trace: list[TrafficRequest], *,
                     ) -> TrafficReport:
     """Drive ``door`` with ``trace`` closed-loop and report.
 
-    Arrivals are scheduled at ``trace[i].t * time_scale`` on the wall
-    clock; request payloads are seeded random bits per tenant.  The
-    front door must already have every tenant in the trace registered.
+    Arrivals are due at ``trace[i].t * time_scale`` after the start on
+    the wall clock, and each request's latency runs from its due time,
+    so a stall that delays later sends shows in their latency; how late
+    each send went out is in ``TrafficReport.lateness_s``.  Request
+    payloads are seeded random bits per tenant.  The front door must
+    already have every tenant in the trace registered.
     """
     rng = np.random.default_rng(seed)
     report = TrafficReport()
     lock = asyncio.Lock()               # report mutation is awaited-only
     n_inputs = {name: t.graph.n_inputs for name, t in door.tenants.items()}
 
-    async def issue(req: TrafficRequest, bits: np.ndarray) -> None:
-        t0 = time.monotonic()
+    async def issue(req: TrafficRequest, bits: np.ndarray,
+                    due: float) -> None:
         try:
             out = await door.submit(req.tenant, bits,
                                     deadline_s=req.deadline_s,
                                     priority=req.priority)
-            latency = time.monotonic() - t0
+            latency = time.monotonic() - due
             async with lock:
                 report.completed += 1
                 report.latencies_s.append(latency)
@@ -219,13 +239,15 @@ async def run_trace(door: FrontDoor, trace: list[TrafficRequest], *,
     start = time.monotonic()
     tasks = []
     for req in trace:
-        delay = start + req.t * time_scale - time.monotonic()
+        due = start + req.t * time_scale
+        delay = due - time.monotonic()
         if delay > 0:
             await asyncio.sleep(delay)
         bits = rng.integers(0, 2, (req.n_samples,
                                    n_inputs[req.tenant])).astype(bool)
         report.offered += 1
-        tasks.append(asyncio.create_task(issue(req, bits)))
+        report.lateness_s.append(max(0.0, time.monotonic() - due))
+        tasks.append(asyncio.create_task(issue(req, bits, due)))
     await asyncio.gather(*tasks)
     report.elapsed_s = time.monotonic() - start
     return report

@@ -11,6 +11,7 @@ dedicated CI job) scales the overload integration test up.
 import asyncio
 import os
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -222,6 +223,32 @@ def test_projected_wait_sheds_infeasible_deadlines(rng):
 # ---------------------------------------------------------------------------
 # fault injection: drop / delay / fail-compile / evict
 # ---------------------------------------------------------------------------
+
+def test_queue_wait_counts_arrival_to_engine_hand_off(rng):
+    """Every dispatch delayed 40 ms: each request's queue wait, from the
+    call of submit to the engine's submit, holds the delay, and stays
+    below its latency; reset_metrics empties the window."""
+    g = _graph(rng)
+
+    async def go():
+        door = _door(fault_policy=FaultPolicy(seed=1, delay_rate=1.0,
+                                              delay_s=0.04))
+        door.register("a", g)
+        async with door:
+            for n in (3, 9, 30):
+                bits = rng.integers(0, 2, (n, g.n_inputs)).astype(bool)
+                assert (await door.submit("a", bits) == g.evaluate(bits)
+                        ).all()
+            m = door.metrics()
+            door.reset_metrics()
+            return m, door.metrics()
+
+    m, after = _run(go())
+    assert 40.0 <= m["queue_wait_p50_ms"] <= m["queue_wait_p99_ms"]
+    assert m["queue_wait_p99_ms"] <= m["latency_p99_ms"]
+    assert after["queue_wait_p50_ms"] is None
+    assert after["queue_wait_p99_ms"] is None
+
 
 def test_injected_drop_sheds(rng):
     g = _graph(rng)
@@ -498,6 +525,45 @@ def test_interarrival_rates_match():
         assert gaps.min() >= 0
         # long-run rate within 10% of the configured mean
         assert abs(gaps.mean() - 0.02) < 0.002, arrival
+
+
+class _StallingDoor:
+    """Stands in for a front door: answers at once, but the first
+    request blocks the event loop for ``stall_s``."""
+
+    def __init__(self, graph, stall_s):
+        self.tenants = {"a": type("T", (), {"graph": graph})()}
+        self.stall_s = stall_s
+        self.calls = 0
+
+    async def start(self):
+        pass
+
+    async def submit(self, tenant, bits, **_kw):
+        self.calls += 1
+        if self.calls == 1:
+            time.sleep(self.stall_s)        # the loop stalls here
+        return bits[:, :1]
+
+
+def test_run_trace_latency_includes_a_stall_of_the_sender(rng):
+    """Latency runs from each request's due time: a request due while
+    the event loop was stalled counts the stall, and the report says how
+    late the generator sent it."""
+    from repro.serve.traffic import TrafficRequest
+
+    stall = 0.3
+    door = _StallingDoor(_graph(rng), stall)
+    trace = [TrafficRequest(t=t, tenant="a", n_samples=4, deadline_s=10.0,
+                            priority=Priority.NORMAL) for t in (0.0, 0.05)]
+    report = _run(run_trace(door, trace, seed=3))
+    assert report.completed == 2 and report.offered == 2
+    # the second request was due at 50 ms and sent after the stall
+    assert max(report.latencies_s) >= stall - 0.05 - 0.01
+    assert report.late_max_ms >= (stall - 0.05 - 0.01) * 1e3
+    assert 0.0 <= report.late_p50_ms <= report.late_max_ms
+    d = report.to_dict()
+    assert d["late_max_ms"] == pytest.approx(report.late_max_ms, abs=1e-3)
 
 
 def test_traffic_pattern_validation():
