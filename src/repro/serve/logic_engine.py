@@ -674,6 +674,9 @@ class LogicRequest:
     #: stage graphs of a chain request (``serve_chain``), retained so an
     #: LRU-evicted chain entry can recompile; ``None`` = single-graph.
     chain: tuple | None = None
+    #: the exception of a wave that carried one of its chunks and raised;
+    #: :meth:`LogicEngine.result` raises it in place of the result.
+    error: Exception | None = None
 
     @property
     def n_samples(self) -> int:
@@ -691,6 +694,18 @@ class _Chunk:
     @property
     def n(self) -> int:
         return self.hi - self.lo
+
+
+@dataclass
+class _Wave:
+    """A launched wave: its chunks with their slot rows, the table that
+    holds those rows until the wave is scattered, and the runner's result
+    (its copy to the host already started)."""
+
+    admitted: list
+    slots: SlotTable
+    out_dev: jax.Array | np.ndarray
+    n_active: int
 
 
 # ---------------------------------------------------------------------------
@@ -786,7 +801,13 @@ class LogicEngine:
         self.shard = bool(mesh is not None and
                           (shard is True or (shard is None and n_dev > 1)))
 
-        self.slots = SlotTable(self.capacity)
+        # two row tables used in turn: the rows of the wave in flight stay
+        # held in one while the next wave admits into the other (the slab
+        # indexes rows 0..capacity-1). ``slots`` is the next wave's table.
+        self.slot_tables = (SlotTable(self.capacity),
+                            SlotTable(self.capacity))
+        self.slots = self.slot_tables[0]
+        self._in_flight: _Wave | None = None
         self.max_retained = max_retained
         self._queues: OrderedDict[tuple, deque[_Chunk]] = OrderedDict()
         self._requests: dict[int, LogicRequest] = {}
@@ -811,6 +832,7 @@ class LogicEngine:
         self.invocations = 0
         self.samples_served = 0
         self._occupancy_sum = 0.0
+        self.overlapped_waves = 0
 
     # -- compilation-target views (read-only; the spec is the source) -------
 
@@ -956,55 +978,98 @@ class LogicEngine:
             self._finished_order = deque(u for u in order if u in retained)
 
     def step(self) -> list[int]:
-        """One invocation wave: admit, execute, scatter back, recycle.
+        """One call of the wave loop; returns the uids it completed.
 
-        Serves the longest-waiting non-empty program queue (FIFO across
-        keys), admitting chunks into slot rows until the table is full,
-        then runs ONE fused fabric invocation for all of them. Returns the
-        uids completed this wave; a call with nothing queued is no wave.
+        A wave serves the longest-waiting non-empty program queue (FIFO
+        across keys), admitting chunks into slot rows until the table is
+        full, and runs ONE fused fabric invocation for all of them.  At
+        most one wave stays in flight between calls: with a wave W in
+        flight and work queued, a call admits, builds and launches the
+        next wave V (starting its result's copy to the host), then
+        fetches and scatters W and returns W's completed uids, leaving V
+        in flight.  So the device runs V while the host fetches and
+        scatters W.  With nothing in flight, a call launches a wave and
+        keeps it in flight only if work is still queued after admission,
+        returning ``[]``; otherwise (a lone request, the queue's last
+        wave) it fetches and scatters the wave at once.  Waves complete
+        in launch order; :attr:`idle` is false while one is in flight,
+        so :meth:`drain` finishes it.  A call with nothing queued or in
+        flight is no wave.
 
-        A wave is the ``jax.profiler`` span ``logic.engine.step``
-        (:data:`STEP_SPAN`) with the stats ``rows`` (samples admitted),
-        ``capacity``, ``chunks`` and ``finished`` (requests completed).
-        Its children, in order and never overlapping:
-        ``logic.engine.admit`` (queue choice and slot acquisition),
-        ``logic.engine.slab`` (the input slab), ``logic.engine.launch``
-        (the fused runner call, up to its returned ``jax.Array``),
-        ``logic.engine.fetch`` (waiting for the device and copying the
-        result to the host) and ``logic.engine.scatter`` (results into
-        their requests, slot release, retirement, occupancy counters).
-        The spans are always on; without an active profiler each costs
-        about a microsecond.
+        A call is the ``jax.profiler`` span ``logic.engine.step``
+        (:data:`STEP_SPAN`).  Its children, in order and never
+        overlapping, are the launched wave's ``logic.engine.admit``
+        (queue choice and slot acquisition), ``logic.engine.slab`` (the
+        input slab) and ``logic.engine.launch`` (the fused runner call,
+        up to its returned ``jax.Array``, and the start of its copy to
+        the host), then the fetched wave's ``logic.engine.fetch``
+        (waiting for its result on the host) and
+        ``logic.engine.scatter`` (results into their requests, slot
+        release, retirement, occupancy counters).  The call that fetches
+        a wave carries its stats: ``rows`` (samples admitted),
+        ``capacity``, ``chunks``, ``finished`` (requests completed) and
+        ``overlapped`` (1 when the wave was launched by an earlier call,
+        so its round trip overlapped host work).  The spans are always
+        on; without an active profiler each costs about a microsecond.
+
+        A wave whose launch or fetch raises fails its own requests
+        (:meth:`result` raises the error), frees its rows and re-raises;
+        a wave in flight beside it stays in flight.
         """
         if self.idle:
             return []
-        with TraceAnnotation(STEP_SPAN, capacity=self.capacity) as wave:
-            with TraceAnnotation(ADMIT_SPAN):
-                key, entry, admitted = self._admit_wave()
-            if not admitted:
-                return []
-            n_active = sum(c.n for c, _ in admitted)
-            with TraceAnnotation(SLAB_SPAN):
-                bits = np.zeros((self.capacity, entry.n_inputs), dtype=bool)
-                for chunk, rows in admitted:
-                    bits[rows] = chunk.req.inputs[chunk.lo:chunk.hi]
-            # hand the numpy slab straight to the jit runner: its C
-            # argument path transfers it far cheaper than an eager
-            # jnp.asarray round trip (which cost more than the kernel
-            # itself at small waves)
-            with TraceAnnotation(LAUNCH_SPAN):
-                out_dev = entry.runners[self._exec_key](bits)
-            with TraceAnnotation(FETCH_SPAN):
-                out = np.asarray(out_dev)
-            with TraceAnnotation(SCATTER_SPAN):
-                finished = self._scatter(key, admitted, out, n_active)
-            wave.set_metadata(rows=n_active, chunks=len(admitted),
-                              finished=len(finished))
+        with TraceAnnotation(STEP_SPAN) as span:
+            held = self._in_flight
+            wave = self._launch() if self._queued else None
+            if held is None:
+                if self._queued:        # work to overlap with: keep it
+                    self._in_flight = wave
+                    return []
+                held = wave
+            else:
+                self._in_flight = wave
+            finished = self._finish(held)
+            overlapped = int(held is not wave)
+            self.overlapped_waves += overlapped
+            span.set_metadata(rows=held.n_active, capacity=self.capacity,
+                              chunks=len(held.admitted),
+                              finished=len(finished), overlapped=overlapped)
         return finished
 
-    def _admit_wave(self) -> tuple[tuple, CompiledEntry, list]:
-        """Pick the longest-waiting non-empty queue and acquire slot rows
-        for its chunks: ``(key, entry, [(chunk, rows)])``."""
+    @property
+    def _queued(self) -> bool:
+        return any(self._queues.values())
+
+    def _launch(self) -> _Wave:
+        """Admit, build the slab and launch the next wave into the free
+        row table, starting its result's copy to the host."""
+        with TraceAnnotation(ADMIT_SPAN):
+            entry, admitted = self._admit_wave()
+        slots = self.slots
+        with TraceAnnotation(SLAB_SPAN):
+            bits = np.zeros((self.capacity, entry.n_inputs), dtype=bool)
+            for chunk, rows in admitted:
+                bits[rows] = chunk.req.inputs[chunk.lo:chunk.hi]
+        # hand the numpy slab straight to the jit runner: its C argument
+        # path transfers it far cheaper than an eager jnp.asarray round
+        # trip (which cost more than the kernel itself at small waves)
+        with TraceAnnotation(LAUNCH_SPAN):
+            try:
+                out_dev = entry.runners[self._exec_key](bits)
+                if isinstance(out_dev, jax.Array):  # a wrapped runner may
+                    out_dev.copy_to_host_async()    # hand back host data
+            except Exception as exc:
+                self._fail(admitted, slots, exc)
+                raise
+        first, second = self.slot_tables
+        self.slots = second if slots is first else first
+        return _Wave(admitted, slots, out_dev,
+                     sum(c.n for c, _ in admitted))
+
+    def _admit_wave(self) -> tuple[CompiledEntry, list]:
+        """Pick the longest-waiting non-empty queue and acquire rows of
+        :attr:`slots` for its chunks: ``(entry, [(chunk, rows)])``.  A
+        queue emptied here leaves the FIFO order of keys."""
         key = next(k for k, q in self._queues.items() if q)
         queue = self._queues[key]
         entry = self.cache.peek(key)
@@ -1023,32 +1088,57 @@ class LogicEngine:
             if rows is None:
                 break
             admitted.append((queue.popleft(), rows))
-        return key, entry, admitted
-
-    def _scatter(self, key: tuple, admitted: list, out: np.ndarray,
-                 n_active: int) -> list[int]:
-        """Results into their requests, rows back to the slot table,
-        completed requests retired, occupancy counted; returns the
-        completed uids."""
-        finished: list[int] = []
-        for chunk, rows in admitted:
-            chunk.req.result[chunk.lo:chunk.hi] = out[rows]
-            chunk.req.pending_chunks -= 1
-            self.slots.release(rows)
-            if chunk.req.pending_chunks == 0:
-                chunk.req.done = True
-                finished.append(chunk.req.uid)
-                self._retire(chunk.req.uid)
-        self.invocations += 1
-        self.samples_served += n_active
-        self._occupancy_sum += n_active / self.capacity
-        if not self._queues[key]:
+        if not queue:
             del self._queues[key]
+        return entry, admitted
+
+    def _finish(self, wave: _Wave) -> list[int]:
+        """Fetch a wave's result and scatter it; returns the completed
+        uids."""
+        with TraceAnnotation(FETCH_SPAN):
+            try:
+                out = np.asarray(wave.out_dev)
+            except Exception as exc:
+                self._fail(wave.admitted, wave.slots, exc)
+                raise
+        with TraceAnnotation(SCATTER_SPAN):
+            finished: list[int] = []
+            for chunk, rows in wave.admitted:
+                if self._chunk_done(chunk, rows, wave.slots, out):
+                    finished.append(chunk.req.uid)
+            self.invocations += 1
+            self.samples_served += wave.n_active
+            self._occupancy_sum += wave.n_active / self.capacity
         return finished
+
+    def _fail(self, admitted: list, slots: SlotTable,
+              exc: Exception) -> None:
+        """A wave that raised: its rows back to their table and its
+        requests failed with ``exc``."""
+        for chunk, rows in admitted:
+            chunk.req.error = exc
+            self._chunk_done(chunk, rows, slots)
+
+    def _chunk_done(self, chunk: _Chunk, rows: np.ndarray, slots: SlotTable,
+                    out: np.ndarray | None = None) -> bool:
+        """One chunk of a wave through: its result rows copied from
+        ``out`` (none for a failed wave), its rows released; True when it
+        completed its request (then retired)."""
+        req = chunk.req
+        if out is not None:
+            req.result[chunk.lo:chunk.hi] = out[rows]
+        slots.release(rows)
+        req.pending_chunks -= 1
+        if req.pending_chunks:
+            return False
+        req.done = True
+        self._retire(req.uid)
+        return True
 
     @property
     def idle(self) -> bool:
-        return not any(self._queues.values())
+        """No work queued and no wave in flight."""
+        return self._in_flight is None and not self._queued
 
     def result(self, uid: int, *, pop: bool = True) -> np.ndarray:
         """Completed request's (n_samples, n_outputs) bool outputs."""
@@ -1065,6 +1155,10 @@ class LogicEngine:
             # entry goes stale and is compacted lazily
             self._retained.discard(uid)
             self._compact_finished()
+        if req.error is not None:
+            raise RuntimeError(
+                f"request {uid} failed in its wave: {req.error!r}") \
+                from req.error
         return req.result
 
     def drain(self) -> None:
@@ -1091,8 +1185,14 @@ class LogicEngine:
         self.invocations = 0
         self.samples_served = 0
         self._occupancy_sum = 0.0
+        self.overlapped_waves = 0
 
     def stats(self) -> dict:
+        """Telemetry since construction or :meth:`reset_telemetry`:
+        ``overlapped_waves`` counts the waves fetched by a later call
+        than launched them (their round trip overlapped host work) and
+        ``overlap_share`` is their share of ``invocations``;
+        ``slot_high_water`` is one wave's densest occupancy."""
         inv = max(1, self.invocations)
         return {
             "capacity": self.capacity,
@@ -1102,6 +1202,8 @@ class LogicEngine:
             "invocations": self.invocations,
             "samples_served": self.samples_served,
             "mean_occupancy": self._occupancy_sum / inv,
-            "slot_high_water": self.slots.high_water,
+            "overlapped_waves": self.overlapped_waves,
+            "overlap_share": self.overlapped_waves / inv,
+            "slot_high_water": max(t.high_water for t in self.slot_tables),
             **{f"cache_{k}": v for k, v in self.cache.stats().items()},
         }

@@ -355,3 +355,144 @@ def test_sharded_parity_multi_device_subprocess():
     except subprocess.TimeoutExpired:
         pytest.skip("multi-device serving smoke exceeded 300s on this host")
     assert "sharded-ok" in out.stdout, out.stderr[-2000:]
+
+
+# ---------------------------------------------------------------------------
+# one wave in flight
+# ---------------------------------------------------------------------------
+
+def test_overlap_engages_only_with_work_queued(rng):
+    """A lone small request is fetched in the call that launched it; with
+    more than a wave queued every wave is fetched by a later call."""
+    g = _graph(rng, n_in=8, n_gates=100, n_out=5)
+    eng = LogicEngine(CompileSpec(n_unit=8), capacity=32)
+    X = rng.integers(0, 2, (20, 8)).astype(bool)
+    assert (eng.serve(g, X) == g.evaluate(X)).all()
+    st = eng.stats()
+    assert (st["invocations"], st["overlapped_waves"],
+            st["overlap_share"]) == (1, 0, 0.0)
+    eng.reset_telemetry()
+    xs = [rng.integers(0, 2, (30, 8)).astype(bool) for _ in range(4)]
+    uids = [eng.submit(g, x) for x in xs]        # 30 + 30 > 32: 4 waves
+    eng.drain()
+    st = eng.stats()
+    assert (st["invocations"], st["overlapped_waves"],
+            st["overlap_share"]) == (4, 4, 1.0)
+    for uid, x in zip(uids, xs):
+        assert (eng.result(uid) == g.evaluate(x)).all()
+
+
+def test_two_keys_bit_exact_with_a_wave_in_flight(rng):
+    """The last wave of one key is in flight while the other key's first
+    launches: both stay bit-exact, and keys are served FIFO."""
+    ga = _graph(rng, n_in=8, n_gates=90, n_out=5)
+    gb = _graph(rng, n_in=11, n_gates=140, n_out=7)
+    eng = LogicEngine(CompileSpec(n_unit=8), capacity=32)
+    Xa = rng.integers(0, 2, (70, 8)).astype(bool)     # waves 32, 32, 6
+    Xb = rng.integers(0, 2, (50, 11)).astype(bool)    # waves 32, 18
+    ua, ub = eng.submit(ga, Xa), eng.submit(gb, Xb)
+    done = []
+    while not eng.idle:
+        done += eng.step()
+    assert done == [ua, ub]
+    assert (eng.result(ua) == ga.evaluate(Xa)).all()
+    assert (eng.result(ub) == gb.evaluate(Xb)).all()
+    assert eng.stats()["overlapped_waves"] == eng.invocations == 5
+
+
+def test_oversized_request_chunks_over_overlapped_waves(rng):
+    """A request of five waves: the first call only launches, each later
+    call fetches the wave before, and the request completes with the
+    fetch of its last chunk."""
+    g = _graph(rng, n_in=8, n_gates=100, n_out=5)
+    eng = LogicEngine(CompileSpec(n_unit=8), capacity=32)
+    X = rng.integers(0, 2, (150, 8)).astype(bool)
+    uid = eng.submit(g, X)
+    calls = []
+    while not eng.idle:
+        calls.append(eng.step())
+    assert calls == [[], [], [], [], [], [uid]]
+    assert (eng.result(uid) == g.evaluate(X)).all()
+    assert eng.stats()["overlapped_waves"] == eng.invocations == 5
+
+
+def test_wave_in_flight_keeps_engine_busy_and_its_rows(rng):
+    """``idle`` is false while a wave is in flight with nothing queued;
+    its rows stay held until it is scattered, and after ``drain`` every
+    row of both tables is free."""
+    g = _graph(rng, n_in=8, n_gates=100, n_out=5)
+    eng = LogicEngine(CompileSpec(n_unit=8), capacity=32)
+    u1 = eng.submit(g, rng.integers(0, 2, (30, 8)).astype(bool))
+    u2 = eng.submit(g, rng.integers(0, 2, (25, 8)).astype(bool))
+    assert eng.step() == []                  # wave 1 launched, kept
+    assert eng.step() == [u1]                # wave 2 launched, 1 fetched
+    assert not eng.idle
+    assert [t.n_active for t in eng.slot_tables] == [0, 25]
+    with pytest.raises(RuntimeError, match="still in flight"):
+        eng.result(u2)
+    eng.drain()
+    assert eng.idle and eng.result(u2).shape == (25, 5)
+    assert all(t.n_free == eng.capacity for t in eng.slot_tables)
+    assert eng.stats()["slot_high_water"] == 30
+
+
+def test_eviction_of_a_key_with_its_wave_in_flight(rng):
+    """The LRU may evict a key whose wave is in flight: the wave keeps
+    its own result and chunks, and the key's queued rest recompiles."""
+    g1 = _graph(rng, n_in=8, n_gates=80, n_out=5)
+    g2 = _graph(rng, n_in=8, n_gates=90, n_out=5)
+    eng = LogicEngine(CompileSpec(n_unit=8), capacity=32, max_programs=1)
+    X1 = rng.integers(0, 2, (70, 8)).astype(bool)     # waves 32, 32, 6
+    X2 = rng.integers(0, 2, (10, 8)).astype(bool)
+    u1, u2 = eng.submit(g1, X1), eng.submit(g2, X2)
+    assert eng.step() == []                  # g1's first wave in flight
+    eng.cache.evict()
+    assert len(eng.cache) == 0
+    eng.drain()
+    assert (eng.result(u1) == g1.evaluate(X1)).all()
+    assert (eng.result(u2) == g2.evaluate(X2)).all()
+    assert eng.stats()["overlapped_waves"] == eng.invocations == 4
+
+
+class _LostResult:
+    """A runner's result whose fetch fails, as a device lost mid-wave."""
+
+    def __array__(self, dtype=None, copy=None):
+        raise RuntimeError("device lost")
+
+
+@pytest.mark.parametrize("fails_at", ["launch", "fetch"])
+def test_failed_wave_fails_only_its_own_requests(rng, fails_at):
+    """Three keys, a wave each: B's wave raises at its launch (A in
+    flight) or at its fetch (C launched, in flight).  B's request fails
+    with the error, the wave beside it stays in flight, and A and C
+    complete bit-exact with every row freed."""
+    graphs = [_graph(rng, n_in=8, n_gates=80 + 10 * i, n_out=5)
+              for i in range(3)]
+    eng = LogicEngine(CompileSpec(n_unit=8), capacity=32)
+    xs = [rng.integers(0, 2, (20, 8)).astype(bool) for _ in graphs]
+    ua, ub, uc = (eng.submit(g, x) for g, x in zip(graphs, xs))
+    runners = eng._entry(graphs[1]).runners
+
+    def broken(bits):
+        if fails_at == "launch":
+            raise RuntimeError("kernel failed to compile")
+        return _LostResult()
+    runners[eng._exec_key] = broken
+    assert eng.step() == []                  # A launched and kept
+    if fails_at == "launch":
+        with pytest.raises(RuntimeError, match="failed to compile"):
+            eng.step()                       # B's launch raises
+    else:
+        assert eng.step() == [ua]            # B launched, A fetched
+        with pytest.raises(RuntimeError, match="device lost"):
+            eng.step()                       # C launched, B's fetch raises
+    assert not eng.idle                      # A or C still in flight
+    eng.drain()
+    with pytest.raises(RuntimeError, match="failed in its wave") as ei:
+        eng.result(ub)
+    assert isinstance(ei.value.__cause__, RuntimeError)
+    assert (eng.result(ua) == graphs[0].evaluate(xs[0])).all()
+    assert (eng.result(uc) == graphs[2].evaluate(xs[2])).all()
+    assert all(t.n_free == eng.capacity for t in eng.slot_tables)
+    assert eng.invocations == 2
