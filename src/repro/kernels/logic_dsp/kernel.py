@@ -132,6 +132,23 @@ def fabric_vmem_bytes(*, n_addr: int, n_unit: int, n_inputs: int,
     return 4 * lanes * rows
 
 
+def hold_rows(stage_meta: tuple, chain: bool) -> int:
+    """Rows of the stage hand-off slab: the widest inner stage's outputs
+    in chain mode, every stage's outputs in parallel mode."""
+    if chain:
+        return max([m[3] for m in stage_meta[:-1]], default=1)
+    return sum(m[3] for m in stage_meta)
+
+
+def vmem_limit_bytes(vmem: int) -> int:
+    """The scoped-VMEM limit a launch that holds ``vmem`` bytes asks
+    Mosaic for: the default while the need plus headroom fits it, else
+    the need plus headroom, never above :data:`VMEM_CAP_BYTES`
+    (:func:`_check_fits` refuses a need beyond the cap first)."""
+    return min(VMEM_CAP_BYTES,
+               max(VMEM_DEFAULT_BYTES, vmem + _VMEM_HEADROOM))
+
+
 def _check_fits(*, vmem: int, smem_records: int, smem_tables: int) -> None:
     if vmem > VMEM_CAP_BYTES:
         raise FabricCapacityError(
@@ -317,10 +334,7 @@ def _launch(src_a, src_b, dst, opcode, step_branch, input_words, out_addrs,
     blk = block_steps(n_unit, max(hi - lo for lo, hi, *_ in stage_meta))
     blocks, first_block = _records(src_a, src_b, dst, opcode, step_branch,
                                    stage_meta, blk)
-    if chain:
-        n_hold = max([m[3] for m in stage_meta[:-1]], default=1)
-    else:
-        n_hold = sum(m[3] for m in stage_meta)
+    n_hold = hold_rows(stage_meta, chain)
     vmem = fabric_vmem_bytes(n_addr=n_addr, n_unit=n_unit, n_inputs=n_inputs,
                              n_outputs=n_outputs, n_hold=n_hold,
                              block_w=block_w)
@@ -353,9 +367,7 @@ def _launch(src_a, src_b, dst, opcode, step_branch, input_words, out_addrs,
         out_shape=jax.ShapeDtypeStruct((n_outputs, w), jnp.int32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
-            vmem_limit_bytes=min(VMEM_CAP_BYTES,
-                                 max(VMEM_DEFAULT_BYTES,
-                                     vmem + _VMEM_HEADROOM))),
+            vmem_limit_bytes=vmem_limit_bytes(vmem)),
         interpret=interpret,
         name="logic_fabric",
     )(jnp.asarray(out_addrs, jnp.int32), jnp.asarray(perm, jnp.int32),

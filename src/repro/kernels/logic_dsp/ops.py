@@ -371,6 +371,29 @@ def _mega_forward_ref(mega: MegaProgram, arrs: dict,
     return jnp.take(cat, arrs["perm"], axis=0)
 
 
+def _mega_block_w(block_w: int, w: int) -> int:
+    """Lanes a grid step of a mega launch over ``w`` words takes: the
+    same small-batch clamp as :func:`forward_words`, one grid step and
+    minimal padding."""
+    return min(block_w, -(-w // _k.SUBLANE) * _k.SUBLANE)
+
+
+def mega_vmem_limit(mega: MegaProgram, w: int,
+                    block_w: int = _k.LANE) -> int:
+    """The scoped-VMEM limit :func:`mega_forward_words`'s launch over
+    ``w`` batch words asks for (``kernel.vmem_limit_bytes``); 0 for a
+    gateless pipeline, which launches no kernel."""
+    if mega.total_steps == 0:
+        return 0
+    chain = mega.mode == "chain"
+    n_outputs = mega.stage_meta[-1][3] if chain else len(mega.output_perm)
+    return _k.vmem_limit_bytes(_k.fabric_vmem_bytes(
+        n_addr=mega.n_addr, n_unit=mega_arrays(mega)["src_a"].shape[1],
+        n_inputs=mega.n_inputs, n_outputs=n_outputs,
+        n_hold=_k.hold_rows(mega.stage_meta, chain),
+        block_w=_mega_block_w(block_w, w)))
+
+
 def mega_forward_words(mega: MegaProgram, words: jnp.ndarray, *,
                        block_w: int = _k.LANE, interpret: bool | None = None,
                        use_ref: bool = False) -> jnp.ndarray:
@@ -379,9 +402,7 @@ def mega_forward_words(mega: MegaProgram, words: jnp.ndarray, *,
     arrs = mega_arrays(mega)
     if use_ref or mega.total_steps == 0:
         return _mega_forward_ref(mega, arrs, words)
-    # same small-batch clamp as forward_words: one grid step, minimal pad
-    block_w = min(block_w,
-                  -(-words.shape[1] // _k.SUBLANE) * _k.SUBLANE)
+    block_w = _mega_block_w(block_w, words.shape[1])
     padded = _pad_words(words, block_w)
     out = _k.mega_pallas_call(
         arrs["src_a"], arrs["src_b"], arrs["dst"], arrs["opcode"],

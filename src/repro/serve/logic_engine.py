@@ -62,7 +62,8 @@ from repro.core.spec import CompileSpec, resolve_spec, _UNSET
 from repro.core.verify import effective_mode, verify_artifact
 from repro.kernels.logic_dsp import kernel as _k
 from repro.kernels.logic_dsp.ops import (mega_arrays, mega_forward_words,
-                                         pack_bits_jnp, unpack_bits_jnp)
+                                         mega_vmem_limit, pack_bits_jnp,
+                                         unpack_bits_jnp)
 from repro.serve.batcher import SlotTable
 from repro.train.sharding import batch_pspec
 
@@ -103,12 +104,15 @@ class CompiledEntry:
     entry adds the registry key and the lazily-attached fused jit
     runners, keyed by engine execution config (mesh/shard/backend/
     capacity) so engines sharing a cache never run another engine's
-    trace — evicted with the entry.
+    trace — evicted with the entry — and, under the same keys, the
+    scoped VMEM each runner's fabric launch asks for (0 where it runs
+    no kernel).
     """
 
     key: tuple
     artifact: CompiledArtifact
     runners: dict = field(default_factory=dict)
+    launch_vmem: dict = field(default_factory=dict)
 
     @property
     def spec(self) -> CompileSpec:
@@ -800,6 +804,7 @@ class LogicEngine:
         # (exercised by tests without multi-device hosts).
         self.shard = bool(mesh is not None and
                           (shard is True or (shard is None and n_dev > 1)))
+        self._n_shards = n_dev if self.shard else 1
 
         # two row tables used in turn: the rows of the wave in flight stay
         # held in one while the next wave admits into the other (the slab
@@ -855,9 +860,17 @@ class LogicEngine:
     # -- program / runner plumbing ------------------------------------------
 
     def _entry(self, graph: LogicGraph) -> CompiledEntry:
-        entry = self.cache.get(graph, self.spec)
+        return self._with_runner(self.cache.get(graph, self.spec))
+
+    def _with_runner(self, entry: CompiledEntry) -> CompiledEntry:
+        """Attach this engine's runner to ``entry``, and the scoped VMEM
+        its launch asks for, once per entry."""
         if self._exec_key not in entry.runners:
             entry.runners[self._exec_key] = self._build_runner(entry)
+            words = self.capacity // self._n_shards // WORD_BITS
+            entry.launch_vmem[self._exec_key] = 0 if self.use_ref else \
+                mega_vmem_limit(entry.artifact.megaprogram(), words,
+                                self.block_w)
         return entry
 
     def _build_runner(self, entry: CompiledEntry) -> Callable:
@@ -900,10 +913,7 @@ class LogicEngine:
     # -- request lifecycle ---------------------------------------------------
 
     def _chain_entry(self, graphs: tuple) -> CompiledEntry:
-        entry = self.cache.get_chain(graphs, self.spec)
-        if self._exec_key not in entry.runners:
-            entry.runners[self._exec_key] = self._build_runner(entry)
-        return entry
+        return self._with_runner(self.cache.get_chain(graphs, self.spec))
 
     def submit(self, graph: LogicGraph, bits: np.ndarray) -> int:
         """Queue a request; returns its uid (serve with :meth:`step`)."""
@@ -1002,8 +1012,10 @@ class LogicEngine:
         (queue choice and slot acquisition), ``logic.engine.slab`` (the
         input slab) and ``logic.engine.launch`` (the fused runner call,
         up to its returned ``jax.Array``, and the start of its copy to
-        the host), then the fetched wave's ``logic.engine.fetch``
-        (waiting for its result on the host) and
+        the host; stats ``in_bytes``, the slab's bytes, and
+        ``vmem_bytes``, the scoped VMEM its fabric launch asks for),
+        then the fetched wave's ``logic.engine.fetch`` (waiting for its
+        result on the host) and
         ``logic.engine.scatter`` (results into their requests, slot
         release, retirement, occupancy counters).  The call that fetches
         a wave carries its stats: ``rows`` (samples admitted),
@@ -1053,7 +1065,8 @@ class LogicEngine:
         # hand the numpy slab straight to the jit runner: its C argument
         # path transfers it far cheaper than an eager jnp.asarray round
         # trip (which cost more than the kernel itself at small waves)
-        with TraceAnnotation(LAUNCH_SPAN):
+        with TraceAnnotation(LAUNCH_SPAN, in_bytes=bits.nbytes,
+                             vmem_bytes=entry.launch_vmem[self._exec_key]):
             try:
                 out_dev = entry.runners[self._exec_key](bits)
                 if isinstance(out_dev, jax.Array):  # a wrapped runner may
@@ -1080,8 +1093,8 @@ class LogicEngine:
             req = queue[0].req
             entry = self._chain_entry(req.chain) if req.chain is not None \
                 else self._entry(req.graph)
-        elif self._exec_key not in entry.runners:
-            entry.runners[self._exec_key] = self._build_runner(entry)
+        else:
+            self._with_runner(entry)
         admitted: list[tuple[_Chunk, np.ndarray]] = []
         while queue:
             rows = self.slots.acquire(queue[0].n)

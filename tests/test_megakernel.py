@@ -27,6 +27,7 @@ from repro.core.scheduler import (build_megaprogram, compile_graph,
 from repro.core.spec import CompileSpec
 from repro.kernels.logic_dsp import kernel as _k
 from repro.kernels.logic_dsp.ops import (mega_forward_words, mega_infer_bits,
+                                         mega_vmem_limit,
                                          logic_infer_bits, pack_bits_jnp,
                                          trace_count, unpack_bits_jnp)
 
@@ -246,6 +247,54 @@ def test_program_that_cannot_fit_raises_with_bytes():
                              a["step_branch"], words, a["output_addrs"],
                              n_addr=300_000)
     assert isinstance(ei.value, PermanentCompileError)
+
+
+#: (n_addr, n_inputs, n_outputs) of the benchmark's netlists compiled at
+#: n_unit 32, as its set-up reports them; each is one parallel stage, so
+#: the hand-off slab holds every output
+LENET5_HEAD, VGG16_CONV2 = (19171, 400, 84), (7861, 576, 64)
+VGG16_CONV8 = (60890, 2304, 512)
+
+
+def _launch_vmem(n_addr, n_in, n_out):
+    return _k.fabric_vmem_bytes(n_addr=n_addr, n_unit=32, n_inputs=n_in,
+                                n_outputs=n_out, n_hold=n_out, block_w=128)
+
+
+@pytest.mark.parametrize("shape", [LENET5_HEAD, VGG16_CONV2])
+def test_narrow_launches_keep_the_default_vmem_limit(shape):
+    """LeNet's and conv2's launches fit Mosaic's default scoped VMEM with
+    the headroom to spare, and ask for that default, 16 MiB."""
+    need = _launch_vmem(*shape)
+    assert need + 2 * 1024 * 1024 <= _k.VMEM_DEFAULT_BYTES
+    assert _k.vmem_limit_bytes(need) == 16 * 1024 * 1024
+
+
+def test_conv8_launch_raises_the_vmem_limit():
+    """conv8's address file alone passes the default: its launch asks
+    for the need plus 2 MiB of headroom, within the core's cap."""
+    need = _launch_vmem(*VGG16_CONV8)
+    limit = _k.vmem_limit_bytes(need)
+    assert limit == need + 2 * 1024 * 1024
+    assert _k.VMEM_DEFAULT_BYTES < limit <= _k.VMEM_CAP_BYTES
+    assert _k.vmem_limit_bytes(_k.VMEM_CAP_BYTES) == _k.VMEM_CAP_BYTES
+
+
+def test_mega_vmem_limit_is_what_the_launch_asks_for(monkeypatch):
+    """The engine's per-program reading equals the limit the launch
+    hands Mosaic, for a narrow batch (block_w clamped) and a wide one."""
+    rng = np.random.default_rng(22)
+    mega = build_megaprogram([compile_graph(
+        _layer(rng, 6, 40, 4), CompileSpec(n_unit=8, optimize="none"))])
+    asked = []
+    real = _k.vmem_limit_bytes
+    monkeypatch.setattr(_k, "vmem_limit_bytes",
+                        lambda v: asked.append(real(v)) or asked[-1])
+    monkeypatch.setattr(_k, "VMEM_DEFAULT_BYTES", 0)
+    for w in (1, 128):
+        mega_forward_words(mega, jnp.zeros((6, w), jnp.int32))
+        launched = asked[-1]
+        assert mega_vmem_limit(mega, w) == launched > 0
 
 
 def test_interpret_resolves_from_backend():

@@ -10,6 +10,7 @@ import pytest
 
 from repro.core.gate_ir import random_graph
 from repro.core.spec import CompileSpec
+from repro.kernels.logic_dsp import kernel
 from repro.serve import FrontDoor
 from repro.serve.frontdoor import COMPLETE_SPAN, DISPATCH_SPAN, ROUTE_SPAN
 from repro.serve.logic_engine import (ADMIT_SPAN, FETCH_SPAN, LAUNCH_SPAN,
@@ -135,3 +136,17 @@ def test_every_wave_says_whether_it_overlapped(traced):
     assert all(st["overlapped"] in (0, 1) for st in stats)
     overlapped = sum(st["overlapped"] for st in stats)
     assert overlapped == door.engine.stats()["overlapped_waves"] >= 1
+
+
+def test_every_launch_carries_its_bytes_and_vmem(traced):
+    """Each ``logic.engine.launch`` says how many bytes of slab it handed
+    to the runner and how much scoped VMEM its fabric launch asks for,
+    the same for every wave of one program."""
+    graph, *_, door, events = traced
+    eng = door.engine
+    stats = [st for n, _, _, st in events if n == LAUNCH_SPAN]
+    assert len(stats) == eng.invocations
+    assert {st["in_bytes"] for st in stats} == {eng.capacity * graph.n_inputs}
+    vmem = eng._entry(graph).launch_vmem[eng._exec_key]
+    assert {st["vmem_bytes"] for st in stats} == {vmem}
+    assert vmem == kernel.VMEM_DEFAULT_BYTES      # a small program

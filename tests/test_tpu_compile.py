@@ -19,12 +19,16 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.gate_ir import random_graph
 from repro.core.scheduler import build_megaprogram, compile_graph
 from repro.core.spec import CompileSpec
+from repro.kernels.logic_dsp import kernel as fabric
 from repro.kernels.logic_dsp.ops import (forward_words, mega_forward_words,
                                          program_arrays)
 from repro.kernels.xnor_gemm import kernel as xnor_kernel
 
 SPEC = CompileSpec(n_unit=32, optimize="none")
 W = 128                                   # one 128-lane word block
+#: VGG16 conv8's committed netlist compiled at n_unit 32, as the
+#: benchmark's set-up reports it
+CONV8_STEPS, CONV8_N_ADDR = 4840, 60890
 
 
 @pytest.fixture(scope="module")
@@ -93,6 +97,37 @@ def test_megakernel_compiles_for_v5e(one_chip, lenet_programs, mode):
         return mega_forward_words(mega, words, interpret=False)
 
     assert "tpu_custom_call" in _compiled_text(run, _words(one_chip, 400))
+
+
+def test_conv8_sized_launch_compiles_above_the_default_vmem(one_chip):
+    """VGG16 conv8's program shape (``configs/vgg16-conv8.npz`` at n_unit
+    32: CONV8_STEPS steps over CONV8_N_ADDR rows, 2304 inputs, 512
+    outputs) holds more VMEM than Mosaic's default scoped limit, and
+    compiles with the limit the launch raises.  Seeded streams stand in
+    for the netlist's: the compile depends on their shapes only."""
+    rng = np.random.default_rng(8)
+    n_in, n_out, n_unit = 2304, 512, 32
+    lo = 2 + n_in
+
+    def stream(low):
+        return rng.integers(low, CONV8_N_ADDR - 1,
+                            (CONV8_STEPS, n_unit)).astype(np.int32)
+
+    opcode = rng.integers(1, 7, (CONV8_STEPS, n_unit)).astype(np.int32)
+    outs = rng.integers(lo, CONV8_N_ADDR - 1, n_out).astype(np.int32)
+
+    def run(words):
+        return fabric.mega_pallas_call(
+            stream(0), stream(0), stream(lo), opcode, opcode[:, 0], words,
+            outs, np.arange(n_out, dtype=np.int32), n_addr=CONV8_N_ADDR,
+            stage_meta=((0, CONV8_STEPS, n_in, n_out, 0),), chain=False,
+            interpret=False)
+
+    need = fabric.fabric_vmem_bytes(n_addr=CONV8_N_ADDR, n_unit=n_unit,
+                                    n_inputs=n_in, n_outputs=n_out,
+                                    n_hold=n_out, block_w=W)
+    assert need > fabric.VMEM_DEFAULT_BYTES
+    assert "tpu_custom_call" in _compiled_text(run, _words(one_chip, n_in))
 
 
 def test_xnor_gemm_compiles_for_v5e(one_chip):
